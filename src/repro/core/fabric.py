@@ -86,6 +86,7 @@ from .aio import (EventLoopFrontend, _encode_body, _encode_response,
 from .api.errors import error_payload
 from .auth import AuthError, TokenManager, bearer_token
 from .durable import DurableStorage
+from .kernels import device_report
 from .replication import (ReplicationClient, ReplicationHub,
                           recover_dir_state, reconcile_with)
 from .server import HopaasServer
@@ -1085,6 +1086,7 @@ def _filter_replay(shadow: InMemoryStorage, key: str,
 # worker process entry point
 # --------------------------------------------------------------------- #
 def _serve_worker(args) -> int:
+    device = device_report()           # fails here, before any thread
     faults.load_from_env()
     role = "follower" if args.follow else "leader"
     faults.set_context(worker=args.worker_id, role=role)
@@ -1134,7 +1136,8 @@ def _serve_worker(args) -> int:
     for sig in (signal.SIGTERM, signal.SIGINT):
         signal.signal(sig, lambda *_a: stop_event.set())
     ready = {"worker": args.worker_id, "port": frontend.port,
-             "pid": os.getpid(), "digest": storage.state_digest(),
+             "pid": os.getpid(), "device": device,
+             "digest": storage.state_digest(),
              "recovery": getattr(storage, "last_recovery", None),
              "role": role, "epoch": storage.lease_epoch,
              "repl_port": hub.port if hub is not None else None}
@@ -1210,14 +1213,16 @@ def _merge_speculation(entries: list[dict[str, Any]]) -> dict[str, Any]:
 
 class _WorkerProc:
     __slots__ = ("wid", "proc", "host", "port", "pid", "root", "digest",
-                 "recovery", "role", "epoch", "repl_port", "replica_k")
+                 "recovery", "role", "epoch", "repl_port", "replica_k",
+                 "device")
 
     def __init__(self, wid: int, proc: subprocess.Popen, host: str,
                  port: int, pid: int, root: str | None,
                  digest: str | None, recovery: Any, *,
                  role: str = "leader", epoch: int = 0,
                  repl_port: int | None = None,
-                 replica_k: int | None = None):
+                 replica_k: int | None = None,
+                 device: dict[str, Any] | None = None):
         self.wid = wid
         self.proc = proc
         self.host = host
@@ -1230,6 +1235,7 @@ class _WorkerProc:
         self.epoch = epoch               # lease epoch reported at ready
         self.repl_port = repl_port       # replication hub port, if any
         self.replica_k = replica_k       # follower slot (None = leader)
+        self.device = device             # sampler device reported at ready
 
 
 class ShardFabric:
@@ -1427,6 +1433,14 @@ class ShardFabric:
             return [(wp.host, wp.port)
                     for _wid, wp in sorted(self._workers.items())]
 
+    def devices(self) -> dict[int, dict[str, Any]]:
+        """Sampler device each live worker reported when it became
+        ready (``kernels.device_report``); empty when inline."""
+        if self.inline:
+            return {}
+        with self._fleet_lock:
+            return {wid: wp.device for wid, wp in self._workers.items()}
+
     def issue_token(self, user: str = "fabric-user",
                     ttl_seconds: float = 24 * 3600.0) -> str:
         return self.tokens.issue(user, ttl_seconds=ttl_seconds)
@@ -1499,6 +1513,9 @@ class ShardFabric:
             cmd += ["--follow", f"{follow[0]}:{follow[1]}"]
         env = dict(os.environ)
         env["REPRO_FABRIC_SECRET"] = self.secret
+        # one process per chip: with several workers (or any follower)
+        # none may reach for the device unless the operator chose it
+        env.setdefault("JAX_PLATFORMS", "cpu")
         src_dir = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = (src_dir + os.pathsep + env["PYTHONPATH"]
@@ -1515,7 +1532,7 @@ class ShardFabric:
                            role=ready.get("role", "leader"),
                            epoch=int(ready.get("epoch") or 0),
                            repl_port=ready.get("repl_port"),
-                           replica_k=replica_k)
+                           replica_k=replica_k, device=ready.get("device"))
 
     def _spawn_follower(self, wid: int) -> _WorkerProc:
         with self._fleet_lock:

@@ -9,6 +9,9 @@ turns it into one (A, D)x(D, B) matmul plus rank-1 terms, which the
 Pallas kernel folds into a single augmented contraction per tile
 (aug_a = [-2·as, |as|², 1], aug_b = [bs, 1, |bs|²]) followed by the
 element-wise Matérn form — no rank-3 intermediate in either backend.
+The contraction runs at ``Precision.HIGHEST``: the expanded square
+cancels, and one bfloat16 MXU pass (the TPU default) errs by ~0.04 in a
+covariance that lies in [0, 1].
 """
 from __future__ import annotations
 
@@ -20,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ._backend import backend as _select_backend
-from ._backend import largest_divisor_block
+from ._backend import tile
 
 _SQRT5 = math.sqrt(5.0)
 
@@ -36,6 +39,7 @@ def _matern_kernel(aa_ref, bb_ref, out_ref):
     bb = bb_ref[...].astype(jnp.float32)               # (bb, D+2)
     d2 = jax.lax.dot_general(
         aa, bb, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # see module doc
         preferred_element_type=jnp.float32)            # (ba, bb) = d²
     out_ref[...] = _matern_form(d2).astype(out_ref.dtype)
 
@@ -45,19 +49,22 @@ def _matern_pallas_impl(aa: jax.Array, bb: jax.Array, *,
                         interpret: bool = False) -> jax.Array:
     A, da = aa.shape
     B, _ = bb.shape
-    ba = largest_divisor_block(A, 128)
-    bb_blk = largest_divisor_block(B, 128)
-    return pl.pallas_call(
+    ba, a_pad = tile(A)
+    bb_blk, b_pad = tile(B)
+    aa = jnp.pad(aa, ((0, a_pad - A), (0, 0)))     # padding sliced off below
+    bb = jnp.pad(bb, ((0, b_pad - B), (0, 0)))
+    out = pl.pallas_call(
         _matern_kernel,
-        grid=(A // ba, B // bb_blk),
+        grid=(a_pad // ba, b_pad // bb_blk),
         in_specs=[
             pl.BlockSpec((ba, da), lambda i, j: (i, 0)),
             pl.BlockSpec((bb_blk, da), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((ba, bb_blk), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((A, B), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((a_pad, b_pad), jnp.float32),
         interpret=interpret,
     )(aa, bb)
+    return out[:A, :B]
 
 
 def matern52_cross(a: jax.Array, b: jax.Array, ls: jax.Array, *,

@@ -27,6 +27,11 @@ scores to -inf; if a whole tile is masked the online rescale wipes its
 (garbage) contribution as soon as a valid tile arrives — callers always
 have >= 1 valid observation.
 
+The expanded square cancels large terms (|xs|², |os|² against 2 xs·os),
+so the contraction runs at ``Precision.HIGHEST``: at the TPU's default,
+one bfloat16 MXU pass, the log-density errs by ~0.9 at 4,096
+observations in 24 dims.
+
 The ``jnp`` fallback uses the same matmul-form math without the tiling.
 """
 from __future__ import annotations
@@ -40,7 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._backend import backend as _select_backend
-from ._backend import largest_divisor_block
+from ._backend import tile
 
 NEG_INF = -1e30
 
@@ -58,6 +63,7 @@ def _parzen_kernel(xa_ref, oa_ref, out_ref, m_scr, l_scr, *,
     oa = oa_ref[...].astype(jnp.float32)               # (bn, D+1)
     s = jax.lax.dot_general(
         xa, oa, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # see module doc
         preferred_element_type=jnp.float32)            # (bc, bn)
 
     m_prev = m_scr[...]                                # (bc, 128)
@@ -82,25 +88,29 @@ def _parzen_pallas(xa: jax.Array, oa: jax.Array, *,
                    interpret: bool = False) -> jax.Array:
     C, da = xa.shape
     N, _ = oa.shape
-    bc = largest_divisor_block(C, 128)
-    bn = largest_divisor_block(N, 128)
-    n_obs_blocks = N // bn
+    bc, cp = tile(C)
+    bn, n_pad = tile(N)
+    xa = jnp.pad(xa, ((0, cp - C), (0, 0)))        # sliced off below
+    if n_pad > N:       # padded observations are masked rows (+LARGE)
+        fill = jnp.zeros((n_pad - N, da), oa.dtype).at[:, -1].set(-NEG_INF)
+        oa = jnp.concatenate([oa, fill])
+    n_obs_blocks = n_pad // bn
     out = pl.pallas_call(
         functools.partial(_parzen_kernel, n_obs_blocks=n_obs_blocks),
-        grid=(C // bc, n_obs_blocks),    # trailing obs axis runs in order
+        grid=(cp // bc, n_obs_blocks),   # trailing obs axis runs in order
         in_specs=[
             pl.BlockSpec((bc, da), lambda ci, ni: (ci, 0)),
             pl.BlockSpec((bn, da), lambda ci, ni: (ni, 0)),
         ],
         out_specs=pl.BlockSpec((bc, 128), lambda ci, ni: (ci, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, 128), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((cp, 128), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((bc, 128), jnp.float32),        # running max
             pltpu.VMEM((bc, 128), jnp.float32),        # running sumexp
         ],
         interpret=interpret,
     )(xa, oa)
-    return out[:, 0]
+    return out[:C, 0]
 
 
 def parzen_log_density(x: jax.Array, obs: jax.Array, mask: jax.Array,

@@ -47,7 +47,7 @@ def _gp_ei(X: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray,
     alpha = jax.scipy.linalg.cho_solve((L, True), yn * mask)
 
     Ks = matern52_cross(cands, X, ls) * mask[None, :]
-    mu = Ks @ alpha
+    mu = jnp.matmul(Ks, alpha, precision=jax.lax.Precision.HIGHEST)
     v = jax.scipy.linalg.solve_triangular(L, Ks.T, lower=True)
     var = jnp.maximum(1.0 - (v ** 2).sum(0), 1e-9)
     sd = jnp.sqrt(var)

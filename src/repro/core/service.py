@@ -28,11 +28,13 @@ from __future__ import annotations
 
 import argparse
 import atexit
+import json
 import os
 import time
 
 from .auth import TokenManager
 from .durable import DurableStorage
+from .kernels import device_report
 from .server import HopaasServer
 from .storage import InMemoryStorage, JournalStorage
 from .transport import HttpServiceRunner
@@ -79,6 +81,10 @@ def _run_fabric(args: argparse.Namespace) -> int:
         print(f"replication: {fabric.replicas} follower(s) per shard, "
               f"mode={fabric.replication}  [{roles}]")
         print(f"health: GET {fabric.url}/api/v2/health")
+    devices = ", ".join(f"w{wid}:{d['platform']}/{d['kernels']}"
+                        for wid, d in sorted(fabric.devices().items()))
+    print(f"sampler devices: {devices}  (fabric workers sample on the CPU "
+          f"unless JAX_PLATFORMS is set; --workers 1 drives the chip)")
     print(f"API token: {token}")
     print("Ctrl-C to stop.")
     try:
@@ -168,6 +174,9 @@ def main(argv: list[str] | None = None) -> int:
                      "engine has a WAL stream to ship)")
         return _run_fabric(args)
 
+    # this process owns the device the samplers run on: say which one,
+    # so a CPU fallback is visible (the fabric parent never touches JAX)
+    device = device_report()
     storage = build_storage(args)
     # a missed shutdown path (exception, sys.exit) must still flush the
     # WAL tail; close() is idempotent so the Ctrl-C path below is safe
@@ -184,6 +193,7 @@ def main(argv: list[str] | None = None) -> int:
     backend = storage.storage_stats()["backend"]
     print(f"HOPAAS service at {runner.url}  ({args.api_workers} API "
           f"workers, frontend={runner.backend}, storage={backend})")
+    print(f"sampler device: {json.dumps(device)}")
     print(f"API token: {token}")
     print("Ctrl-C to stop.")
     try:

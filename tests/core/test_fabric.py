@@ -12,6 +12,7 @@ from repro.core import (Client, ClientStudy, DurableStorage, HopaasServer,
                         HttpServiceRunner, HttpTransport, RetryPolicy,
                         ShardFabric, ShardedHttpTransport, TokenManager,
                         WalDirectoryLockedError, suggestions)
+from repro.core import fabric as fabric_mod
 from repro.core.fabric import HashRing, RouteTable, classify_target
 from repro.core.storage import InMemoryStorage
 
@@ -142,8 +143,38 @@ def test_fabric_routes_both_surfaces_and_gathers():
         page = cl.trials_page(studies[0].study_key, limit=1)
         assert len(page["trials"]) == 1
         assert fab.stats()["dispatcher"]["proxied"] > 0
+        # every worker's ready line named its sampler device: the CPU,
+        # since no fabric child may take the chip by default
+        devices = fab.devices()
+        assert sorted(devices) == [0, 1]
+        assert all(d["platform"] == "cpu" for d in devices.values())
     finally:
         fab.stop()
+
+
+class _SpawnStopped(Exception):
+    pass
+
+
+@pytest.mark.parametrize("preset,expected", [(None, "cpu"), ("tpu", "tpu")])
+def test_spawn_keeps_children_off_the_chip(monkeypatch, preset, expected):
+    """Children sample on the CPU unless the operator set JAX_PLATFORMS:
+    one chip serves one process."""
+    if preset is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", preset)
+    seen = {}
+
+    def fake_popen(cmd, stdout, env):
+        seen.update(env)
+        raise _SpawnStopped
+
+    monkeypatch.setattr(fabric_mod.subprocess, "Popen", fake_popen)
+    fab = ShardFabric(workers=2, storage="memory")
+    with pytest.raises(_SpawnStopped):
+        fab._spawn(0)
+    assert seen["JAX_PLATFORMS"] == expected
 
 
 def test_sharded_transport_skips_the_router_hop():

@@ -1,7 +1,12 @@
 """Acquisition-kernel equivalence: the Pallas kernels (interpret mode on
 CPU) and the matmul-form jnp fallbacks must both match the naive rank-3
 reference formulations the seed code used."""
+import json
 import math
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -9,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core.kernels import backend, matern52_cross, parzen_log_density
+from repro.core.kernels._backend import CACHE_DIR
 
 TOL = dict(rtol=2e-4, atol=2e-4)
 
@@ -43,6 +49,7 @@ def _case(c, n, d, n_valid, seed=0):
     (64, 32, 5, 20),        # partial mask
     (128, 256, 3, 256),     # full mask, multiple obs tiles
     (256, 512, 11, 300),    # masked tail tiles
+    (200, 300, 5, 250),     # unaligned counts: padded candidate + obs tiles
 ])
 def test_parzen_matches_naive(backend_name, c, n, d, n_valid):
     x, obs, mask, bw = _case(c, n, d, n_valid)
@@ -52,7 +59,8 @@ def test_parzen_matches_naive(backend_name, c, n, d, n_valid):
 
 
 @pytest.mark.parametrize("backend_name", ["jnp", "pallas_interpret"])
-@pytest.mark.parametrize("a,b,d", [(8, 8, 2), (64, 32, 5), (256, 128, 7)])
+@pytest.mark.parametrize("a,b,d", [(8, 8, 2), (64, 32, 5), (256, 128, 7),
+                                   (300, 200, 6)])   # unaligned: padded
 def test_matern_matches_naive(backend_name, a, b, d):
     rng = np.random.default_rng(1)
     xa = jnp.asarray(rng.uniform(size=(a, d)), jnp.float32)
@@ -87,3 +95,41 @@ def test_backend_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_HPO_KERNELS", "bogus")
     with pytest.raises(ValueError):
         backend()
+
+
+@pytest.mark.parametrize("cache_var", [False, True])
+def test_service_banner_reports_device_and_cache(tmp_path, cache_var):
+    """The single-process service names where its samplers run (cpu/jnp
+    under JAX_PLATFORMS=cpu) and where compiled programs are cached."""
+    repo = Path(__file__).resolve().parents[2]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONUNBUFFERED="1",
+               PYTHONPATH=str(repo / "src"))
+    for var in ("REPRO_HPO_KERNELS", "REPRO_WORKERS", "REPRO_REPLICAS",
+                "JAX_COMPILATION_CACHE_DIR"):
+        env.pop(var, None)
+    expected_cache = repo / ".jax_cache"
+    if cache_var:
+        expected_cache = tmp_path / "jax-cache"
+        env["JAX_COMPILATION_CACHE_DIR"] = str(expected_cache)
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "repro.core.service", "--port", "0",
+         "--workers", "1"], env=env, stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT, text=True)
+    lines = []
+    try:
+        for line in proc.stdout:        # EOF if the service dies
+            lines.append(line)
+            if line.startswith("API token:"):
+                break
+    finally:
+        proc.kill()
+        proc.wait(timeout=10)
+    prefix = "sampler device: "
+    reports = [json.loads(ln[len(prefix):]) for ln in lines
+               if ln.startswith(prefix)]
+    assert len(reports) == 1, "".join(lines)
+    device = reports[0]
+    assert (device["platform"], device["kind"]) == ("cpu", "cpu")
+    assert device["count"] >= 1 and device["kernels"] == "jnp"
+    assert device["cache"] == str(expected_cache)
+    assert CACHE_DIR == repo / ".jax_cache"
