@@ -64,6 +64,7 @@ import time
 import zlib
 from typing import Any
 
+from . import tracing
 from .api.errors import error_payload
 from .auth import bearer_token
 
@@ -276,6 +277,13 @@ def _parse_one(conn: _Connection) -> tuple | None:
 
 _STUDY_PREFIX = "/api/v2/studies/"
 _TRIAL_PREFIX = "/api/v2/trials/"
+# span of one request, by the action suffix of its target
+_REQUEST_SPANS = {"ask": "http.ask", "ask_batch": "http.ask",
+                  "report": "http.report", "tell": "http.tell"}
+
+
+def _request_span(target: str) -> str:
+    return _REQUEST_SPANS.get(target.rpartition(":")[2], "http.request")
 
 
 def _study_key_of_target(target: str) -> str | None:
@@ -304,6 +312,8 @@ class _Lane(threading.Thread):
         self.handled = 0                     # stats (single-writer)
         self.inline = 0                      # requests run on the IO thread
         self.cache_hits = 0
+        self.wait_ns = 0                     # queued, IO thread to dequeue
+        self.busy_ns = 0                     # executing requests
 
     def run(self) -> None:
         fe = self.frontend
@@ -312,8 +322,15 @@ class _Lane(threading.Thread):
             if item is None:
                 return
             self.busy = True
+            self.wait_ns += time.perf_counter_ns() - item[-1]
             fe._execute(self, item)
             self.busy = False
+
+    def stats(self) -> dict[str, int]:
+        return {"handled": self.handled, "inline": self.inline,
+                "cache_hits": self.cache_hits,
+                "queued": self.queue.qsize(), "wait_ns": self.wait_ns,
+                "busy_ns": self.busy_ns}
 
 
 class EventLoopFrontend:
@@ -446,26 +463,31 @@ class EventLoopFrontend:
                 "requests": sum(l.handled for l in self._lanes),
                 "inline_requests": sum(l.inline for l in self._lanes),
                 "cache_hits": sum(l.cache_hits for l in self._lanes),
-                "cache_entries": len(self._study_cache)}
+                "cache_entries": len(self._study_cache),
+                "per_lane": [l.stats() for l in self._lanes]}
 
     # ------------------------------------------------------------------ #
     # dispatch (lane threads; also the IO thread via the inline path)
     # ------------------------------------------------------------------ #
     def _execute(self, lane: _Lane, item: tuple) -> None:
         """Run one queued request to completion (response + flush)."""
-        conn, slot, method, target, headers, body, keep_alive = item
-        try:
-            response = self._handle(lane, method, target, headers, body,
-                                    keep_alive)
-        except Exception as e:       # the frontend never drops a socket
-            blob = _encode_body(error_payload(
-                "internal", f"{type(e).__name__}: {e}"))
-            response = _encode_response(500, blob, close=not keep_alive,
-                                        head_only=method == "HEAD")
+        start = time.perf_counter_ns()
+        conn, slot, method, target, headers, body, keep_alive, _ = item
+        with tracing.span(_request_span(target)):
+            try:
+                response = self._handle(lane, method, target, headers,
+                                        body, keep_alive)
+            except Exception as e:   # the frontend never drops a socket
+                blob = _encode_body(error_payload(
+                    "internal", f"{type(e).__name__}: {e}"))
+                response = _encode_response(500, blob,
+                                            close=not keep_alive,
+                                            head_only=method == "HEAD")
         lane.handled += 1
         slot.data = response
         slot.close_after = not keep_alive
         self._complete(conn)
+        lane.busy_ns += time.perf_counter_ns() - start
 
     def _handle(self, lane: _Lane, method: str, target: str,
                 headers: dict[str, str], body_bytes: bytes,
@@ -640,7 +662,8 @@ class EventLoopFrontend:
                         pass
                 else:
                     if events & selectors.EVENT_READ:
-                        self._on_read(conn)
+                        with tracing.span("http.read"):
+                            self._on_read(conn)
                     if events & selectors.EVENT_WRITE and not conn.closed:
                         self._on_write(conn)
             self._drain_done()
@@ -707,6 +730,7 @@ class EventLoopFrontend:
             return
         conn.inbuf += data
         dispatches = []
+        now = time.perf_counter_ns()
         with conn.lock:
             while True:
                 try:
@@ -726,8 +750,8 @@ class EventLoopFrontend:
                 method, target, headers, body, keep_alive = request
                 slot = _Pending()
                 conn.pending.append(slot)
-                dispatches.append(
-                    (conn, slot, method, target, headers, body, keep_alive))
+                dispatches.append((conn, slot, method, target, headers,
+                                   body, keep_alive, now))
             if (len(conn.pending) >= _MAX_PENDING
                     or len(conn.outbuf) >= _MAX_OUTBUF):
                 conn.throttled = True      # stop reading until drained

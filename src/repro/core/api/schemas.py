@@ -510,6 +510,12 @@ class HealthResponse(Schema):
                   "precompute rounds/errors"),
         Field("workers", "list", nullable=True, item_kind="dict",
               doc="fabric router only: per-worker health"),
+        Field("frontend", "dict", nullable=True,
+              doc="HTTP frontend counters: request totals and, per "
+                  "dispatch lane, requests handled (inline ones run "
+                  "on the IO thread), queue depth, and the ns requests "
+                  "waited in the lane's queue and the lane spent "
+                  "executing them"),
     )
 
 

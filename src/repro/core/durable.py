@@ -58,7 +58,7 @@ import threading
 import time
 from typing import Any
 
-from . import faults
+from . import faults, tracing
 from .storage import (CorruptJournalError, InMemoryStorage,
                       load_journal_file)
 
@@ -342,37 +342,39 @@ class DurableStorage(InMemoryStorage):
     def _log(self, record: dict[str, Any]) -> None:
         if self._replaying:
             return
-        # strict JSON: NaN/Infinity would make the segment unreadable
-        text = json.dumps(record, allow_nan=False)
-        line = (text + "\n").encode()
-        pub = 0
-        # sampled under the journal lock: attach_replicator can swap the
-        # hub concurrently (promotion), and the ack wait below must go to
-        # the hub that assigned ``pub``, not whichever is current by then
-        rep = None
-        semi = False
-        with self._journal_lock:
-            if self._closed:
-                return
-            f = self._active_file
-            f.write(line)
-            f.flush()                   # in the OS before we advance seq
-            self._seq += 1
-            seq = self._seq
-            self._written_seq = seq
-            self._active_size += len(line)
-            self._records += 1
-            self._bytes += len(line)
-            rep = self._replicator
-            semi = self._semisync
-            if rep is not None:
-                # under the journal lock: stream position order is
-                # exactly file order (publish is O(1), no I/O)
-                pub = rep.publish(text)
-            if self._active_size >= self.segment_bytes:
-                self._rotate_locked()
-            if self.fsync_mode is FsyncMode.GROUP:
-                self._start_flusher()
+        with tracing.span("wal.append"):
+            # strict JSON: NaN/Infinity would make the segment unreadable
+            text = json.dumps(record, allow_nan=False)
+            line = (text + "\n").encode()
+            pub = 0
+            # sampled under the journal lock: attach_replicator can swap
+            # the hub concurrently (promotion), and the ack wait below
+            # must go to the hub that assigned ``pub``, not whichever is
+            # current by then
+            rep = None
+            semi = False
+            with self._journal_lock:
+                if self._closed:
+                    return
+                f = self._active_file
+                f.write(line)
+                f.flush()               # in the OS before we advance seq
+                self._seq += 1
+                seq = self._seq
+                self._written_seq = seq
+                self._active_size += len(line)
+                self._records += 1
+                self._bytes += len(line)
+                rep = self._replicator
+                semi = self._semisync
+                if rep is not None:
+                    # under the journal lock: stream position order is
+                    # exactly file order (publish is O(1), no I/O)
+                    pub = rep.publish(text)
+                if self._active_size >= self.segment_bytes:
+                    self._rotate_locked()
+                if self.fsync_mode is FsyncMode.GROUP:
+                    self._start_flusher()
         if self.fsync_mode is FsyncMode.ALWAYS:
             self._ensure_durable(seq)
         if pub and semi:
@@ -397,7 +399,8 @@ class DurableStorage(InMemoryStorage):
             synced = False
             try:
                 faults.crash("crash_before_fsync")
-                os.fsync(f.fileno())
+                with tracing.span("wal.fsync"):
+                    os.fsync(f.fileno())
                 faults.crash("crash_after_fsync")
                 synced = True
             finally:
@@ -573,7 +576,7 @@ class DurableStorage(InMemoryStorage):
         then are the old snapshot and folded segments deleted, so a crash
         at any point leaves a recoverable directory.
         """
-        with self._compact_lock:
+        with tracing.span("wal.compact"), self._compact_lock:
             if self._stop.is_set():
                 # a straggler compaction after close() would delete files
                 # under a DurableStorage re-opened on the same directory

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..kernels import parzen_log_density
 from ..obs_cache import check_liar, liar_value
 from ..obs_cache import pad_pow2 as _pad_pow2
@@ -161,13 +162,16 @@ class TPESampler(Sampler):
                  direction: Direction, rng: np.random.Generator,
                  k: int, cache: Any = None) -> np.ndarray | None:
         """(k, D) unit-cube proposals, or None while still in startup."""
-        split = self._split_observations(space, trials, direction, cache)
-        if split is None:
-            return None
-        xg, mg, xb, mb = split
-        key = jax.random.PRNGKey(int(rng.integers(0, 2**31 - 1)))
-        u = _tpe_propose(xg, mg, xb, mb, key, self._pool(k))
-        return np.asarray(u[:k])
+        with tracing.span("tpe.propose"):
+            split = self._split_observations(space, trials, direction,
+                                             cache)
+            if split is None:
+                return None
+            xg, mg, xb, mb = split
+            key = jax.random.PRNGKey(int(rng.integers(0, 2**31 - 1)))
+            u = _tpe_propose(xg, mg, xb, mb, key, self._pool(k))
+            with tracing.span("tpe.readback"):
+                return np.asarray(u[:k])
 
     def _pool(self, k: int) -> int:
         """Candidate-pool size for a top-``k`` draw: at least 4x the
@@ -229,10 +233,12 @@ class TPESampler(Sampler):
         got = 0
         while got < n:
             k = min(chunk, n - got)
-            xg, mg, xb, mb = self._split_xy(space, X, y)
-            key = jax.random.PRNGKey(int(rng.integers(0, 2**31 - 1)))
-            u = np.asarray(_tpe_propose(xg, mg, xb, mb, key,
-                                        self._pool(k))[:k])
+            with tracing.span("tpe.propose"):
+                xg, mg, xb, mb = self._split_xy(space, X, y)
+                key = jax.random.PRNGKey(int(rng.integers(0, 2**31 - 1)))
+                u = _tpe_propose(xg, mg, xb, mb, key, self._pool(k))
+                with tracing.span("tpe.readback"):
+                    u = np.asarray(u[:k])
             chunks.append(u)
             got += k
             if got < n:

@@ -55,7 +55,7 @@ from typing import Any, Callable
 
 import numpy as np
 
-from . import faults
+from . import faults, tracing
 from .api import ApiError, build_openapi, build_router
 from .api.router import Router
 from .auth import TokenManager
@@ -372,6 +372,9 @@ class HopaasServer:
     # fabric workers replace this with a callable merging their
     # role/epoch/replication view into the health resource
     health_hook: Callable[[], dict[str, Any]] | None = None
+    # the HTTP runner hosting this worker sets this to its frontend's
+    # per-lane counters (``HttpServiceRunner.frontend_stats``)
+    frontend_hook: Callable[[], dict[str, Any]] | None = None
 
     def _lease_deadline(self) -> float:
         """Lease stamp for a suggested/heartbeating trial.  The
@@ -384,8 +387,9 @@ class HopaasServer:
 
     def op_health(self) -> dict[str, Any]:
         """Machine-readable readiness (``GET /api/v2/health``): role,
-        lease epoch, replication lag, WAL/fsync stats — what a load
-        balancer or the fabric monitor needs to pick a backend."""
+        lease epoch, replication lag, WAL/fsync stats, the HTTP
+        frontend's lane counters — what a load balancer or the fabric
+        monitor needs to pick a backend."""
         stats = self.storage.storage_stats()
         storage_keys = ("backend", "n_studies", "fsync", "wal_records",
                         "wal_bytes", "fsyncs", "group_commits",
@@ -399,6 +403,8 @@ class HopaasServer:
             "replication": stats.get("replication"),
             "storage": {k: stats[k] for k in storage_keys if k in stats},
             "speculation": self.speculation_stats(),
+            "frontend": (None if self.frontend_hook is None
+                         else self.frontend_hook()),
         }
         hook = self.health_hook
         if hook is not None:
@@ -460,12 +466,16 @@ class HopaasServer:
         if ctx is None:
             raise ApiError(404, "study_not_found",
                            f"unknown study {study_key!r}")
-        with ctx.lock:
+        with tracing.span("study.lock_wait"):
+            ctx.lock.acquire()
+        try:
             if parallelism:
                 ctx.parallelism = max(ctx.parallelism,
                                       min(int(parallelism), 4096))
             self._sweep_study(ctx.key, time.time())
             trials = self._start_trials(ctx, n, worker_id)
+        finally:
+            ctx.lock.release()
         return [self.trial_resource(t) for t in trials]
 
     def op_tell(self, uid: str, value: Any = None,
@@ -484,7 +494,10 @@ class HopaasServer:
         trial = self.storage.get_trial(uid)
         if trial is None:
             raise ApiError(404, "trial_not_found", f"unknown trial {uid!r}")
-        with self.storage.study_lock(trial.study_key):
+        lock = self.storage.study_lock(trial.study_key)
+        with tracing.span("study.lock_wait"):
+            lock.acquire()
+        try:
             if idempotency_key:
                 prior = self.storage.idempotent_result(
                     trial.study_key, idempotency_key)
@@ -517,6 +530,8 @@ class HopaasServer:
                     finished_at=time.time(), lease_deadline=None,
                     idem=(None if not idempotency_key
                           else (idempotency_key, out)))
+        finally:
+            lock.release()
         # a finalize is exactly the event that invalidates precomputed
         # proposals: new observation, smaller pending set
         self._notify_speculator(self._peek_context(trial.study_key))
@@ -555,7 +570,9 @@ class HopaasServer:
             raise ApiError(404, "study_not_found",
                            f"study {trial.study_key!r} for trial "
                            f"{uid!r} is not resolvable")
-        with ctx.lock:
+        with tracing.span("study.lock_wait"):
+            ctx.lock.acquire()
+        try:
             if trial.state != TrialState.RUNNING:
                 # zombie worker: its lease was revoked (or the trial pruned)
                 # while it was away — instruct it to abandon the trial.
@@ -566,11 +583,15 @@ class HopaasServer:
             self.storage.update_trial(
                 uid, intermediate=(int(step), float(value)),
                 lease_deadline=self._lease_deadline())
-            prune = bool(ctx.pruner.should_prune(study, trial, int(step)))
+            with tracing.span("pruner.should_prune"):
+                prune = bool(ctx.pruner.should_prune(study, trial,
+                                                     int(step)))
             if prune:
                 self.storage.update_trial(
                     uid, state=TrialState.PRUNED, finished_at=time.time(),
                     lease_deadline=None)
+        finally:
+            ctx.lock.release()
         if prune:
             self._notify_speculator(ctx)
         return {"uid": uid, "should_prune": prune}
@@ -609,7 +630,8 @@ class HopaasServer:
             if getattr(ctx.sampler, "uses_cache", False):
                 # O(1) when nothing completed since the last ask; O(new)
                 # otherwise — never a rescan of the trial list
-                kwargs["cache"] = ctx.cache.sync(self.storage, ctx.key)
+                with tracing.span("obs_cache.sync"):
+                    kwargs["cache"] = ctx.cache.sync(self.storage, ctx.key)
             # cooperative overprovisioning: a miss already pays the
             # lock + KDE cost for a top-1 draw, and widening the same
             # fused evaluation to top-(1+extra) is nearly free — the

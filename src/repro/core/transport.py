@@ -260,6 +260,8 @@ class HttpServiceRunner:
             raise ValueError(f"unknown frontend backend {self.backend!r} "
                              "(expected 'evloop' or 'threaded')")
         self.host, self.port = self._frontend.host, self._frontend.port
+        for w in self.workers:             # served under health "frontend"
+            w.frontend_hook = self.frontend_stats
 
     def start(self) -> "HttpServiceRunner":
         for fe in self._shards:
