@@ -778,9 +778,13 @@ class InMemoryStorage:
     def storage_stats(self) -> dict[str, Any]:
         """Backend + durability statistics (exposed on /api/v2/version)."""
         with self._registry_lock:
-            n_studies = len(self._shards)
-        return {"backend": "memory", "n_studies": n_studies,
-                "trial_scans": self.trial_scans}
+            studies = [shard.study for shard in self._shards.values()]
+        return {"backend": "memory", "n_studies": len(studies),
+                "trial_scans": self.trial_scans,
+                "report_view_queries": sum(s.report_view_queries
+                                           for s in studies),
+                "report_view_builds": sum(s.report_view_builds
+                                          for s in studies)}
 
     # -- journal hook -----------------------------------------------------
     def _log(self, record: dict[str, Any]) -> None:  # overridden by JournalStorage

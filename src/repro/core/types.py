@@ -15,6 +15,7 @@ import enum
 import hashlib
 import json
 import time
+from bisect import bisect_left, insort
 from typing import Any
 
 
@@ -140,6 +141,32 @@ class StudyConfig:
         return cls(**d)
 
 
+class StepView:
+    """Ascending ``sign * value`` of every report at one step, kept sorted
+    on report so that an order statistic is an index.  A NaN has no place
+    in an order: NaNs are only counted."""
+    __slots__ = ("values", "nans")
+
+    def __init__(self, values: list[float]):
+        self.values = sorted(v for v in values if v == v)
+        self.nans = len(values) - len(self.values)
+
+    def __len__(self) -> int:
+        return len(self.values) + self.nans
+
+    def add(self, v: float) -> None:
+        if v != v:
+            self.nans += 1
+        else:
+            insort(self.values, v)
+
+    def remove(self, v: float) -> None:
+        if v != v:
+            self.nans -= 1
+        else:
+            del self.values[bisect_left(self.values, v)]
+
+
 @dataclasses.dataclass
 class Study:
     config: StudyConfig
@@ -157,6 +184,15 @@ class Study:
     # built on first SHA/hyperband query, then maintained per report
     _rung_cache: dict[tuple[int, float], dict[str, float]] = dataclasses.field(
         default_factory=dict, init=False, repr=False, compare=False)
+    # step -> sign -> sorted sign*values of the step's reports; built on
+    # the first percentile-pruner query at that step, then kept on report
+    _step_views: dict[int, dict[float, StepView]] = dataclasses.field(
+        default_factory=dict, init=False, repr=False, compare=False)
+    # how often the percentile pruner read a view, and built one from scratch
+    report_view_queries: int = dataclasses.field(
+        default=0, init=False, repr=False, compare=False)
+    report_view_builds: int = dataclasses.field(
+        default=0, init=False, repr=False, compare=False)
     _indexed_trials: int = dataclasses.field(
         default=0, init=False, repr=False, compare=False)
     # True only for studies owned by a storage layer, which routes every
@@ -205,6 +241,7 @@ class Study:
         self._step_reports = idx
         self._last_steps = last
         self._rung_cache = {}
+        self._step_views = {}
         self._indexed_trials = len(self.trials)
 
     def note_trial_added(self) -> None:
@@ -219,8 +256,13 @@ class Study:
                 or self._indexed_trials != len(self.trials)):
             return                      # stale: next query rebuilds anyway
         reports = self._step_reports.setdefault(step, {})
-        re_report = uid in reports
+        old = reports.get(uid)
+        re_report = old is not None
         reports[uid] = value
+        for sign, view in self._step_views.get(step, {}).items():
+            if re_report:
+                view.remove(sign * old)
+            view.add(sign * value)
         if step > self._last_steps.get(uid, -1):
             self._last_steps[uid] = step
         for (resource, sign), rung in self._rung_cache.items():
@@ -243,6 +285,22 @@ class Study:
         """{trial_uid: latest value reported at ``step``} from the index."""
         self._ensure_index()
         return self._step_reports.get(step, {})
+
+    def step_view(self, step: int, sign: float,
+                  uid: str) -> tuple[StepView, float | None]:
+        """The sorted view of ``sign * value`` over every report at
+        ``step``, and ``uid``'s own ``sign * value`` in it (None when
+        ``uid`` did not report there)."""
+        self._ensure_index()
+        reports = self._step_reports.get(step, {})
+        views = self._step_views.setdefault(step, {})
+        view = views.get(sign)
+        if view is None:
+            view = views[sign] = StepView([sign * v for v in reports.values()])
+            self.report_view_builds += 1
+        self.report_view_queries += 1
+        own = reports.get(uid)
+        return view, (None if own is None else sign * own)
 
     def _rung_snapshot(self, resource: int, sign: float) -> dict[str, float]:
         self._ensure_index()
