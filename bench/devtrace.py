@@ -17,8 +17,9 @@ from them, over the window ``[0, window_ns)`` of that clock:
 * ``top_ops``: the ten device operations that took most time;
 * ``idle_gaps``: the device's idle time split by what the host was doing
   meanwhile: each gap goes to the host event (JAX's own dispatch,
-  transfer and compile events) that overlaps most of it, or to
-  ``host (no event)``; the ten largest shares.
+  transfer and compile events, not the program spans named in
+  ``spans``) that overlaps most of it, or to ``host (no event)``; the ten
+  largest shares.
 """
 from __future__ import annotations
 
@@ -86,7 +87,7 @@ def _clip(events: list, w: int) -> np.ndarray:
     return a[a[:, 1] > a[:, 0]]
 
 
-def reduce(trace: dict, window_ns: int) -> dict:
+def reduce(trace: dict, window_ns: int, spans: tuple = ()) -> dict:
     device_planes = [p for p in trace["planes"]
                      if DEVICE_PLANE.match(p["name"])]
     busy, programs, kernels, ops = [], {}, {}, {}
@@ -124,11 +125,15 @@ def reduce(trace: dict, window_ns: int) -> dict:
             "programs": programs, "kernels": kernels,
             "top_ops": sorted(([n, s] for n, s in ops.items()),
                               key=lambda x: -x[1])[:10],
-            "idle_gaps": attribute_gaps(gaps, _host_events(trace,
-                                                           window_ns))}
+            "idle_gaps": attribute_gaps(gaps, _host_events(trace, window_ns,
+                                                           spans))}
 
 
-def _host_events(trace: dict, window_ns: int) -> list:
+def _host_events(trace: dict, window_ns: int, spans: tuple) -> list:
+    """JAX's own host events in the window.  The program's spans
+    (``spans``) are left out: ``bench/spans.py`` reads them, and the
+    request span around each gap would outweigh every event inside it."""
+    service = frozenset(spans)
     out = []
     for plane in trace["planes"]:
         if not plane["name"].startswith("/host:"):
@@ -136,7 +141,7 @@ def _host_events(trace: dict, window_ns: int) -> list:
         for line in plane["lines"]:
             out += [e for e in line["events"]
                     if e[2] < MAX_HOST_EVENT_NS and e[1] < window_ns
-                    and e[1] + e[2] > 0]
+                    and e[1] + e[2] > 0 and e[0] not in service]
     return out
 
 

@@ -1,9 +1,11 @@
 """Finds the benchmark's parts by the names ``BENCHMARK.json`` gives them.
 
 A configuration is ``configs/<name>.json``, a traffic mix
-``mixes/<name>.json`` and a per-layer metric a reader
-``metrics/<name>.py`` with a ``read(rec)`` function, all beside this file.
-A cell, mix or metric is added with files and entries alone.
+``mixes/<name>.json``, a per-layer metric a reader ``metrics/<name>.py``
+with a ``read(rec)`` function and a sampler, named by a configuration's
+``studies.sampler.name``, a plug-in ``samplers/<name>.py`` (its contract
+is in ``samplers/__init__.py``), all beside this file.  A cell, mix,
+metric or sampler is added with files and entries alone.
 """
 from __future__ import annotations
 
@@ -24,10 +26,16 @@ def check_name(name: str) -> str:
     return name
 
 
+WHAT = {"configs": "configuration", "mixes": "traffic mix",
+        "metrics": "metric reader", "samplers": "sampler plug-in"}
+
+
 def _file(kind: str, name: str, suffix: str) -> Path:
     path = HERE / kind / (check_name(name) + suffix)
     if not path.is_file():
-        raise FileNotFoundError(f"no {kind[:-1]} named {name!r} ({path})")
+        raise FileNotFoundError(
+            f"no {WHAT[kind]} named {name!r} "
+            f"({path.relative_to(HERE.parent).as_posix()})")
     return path
 
 
@@ -46,14 +54,26 @@ def mix(name: str) -> dict:
     return json.loads(_file("mixes", name, ".json").read_text())
 
 
-def metric_reader(name: str):
-    """The ``read(rec) -> float | None`` function of a per-layer metric."""
-    path = _file("metrics", name, ".py")
+def _module(prefix: str, name: str, path: Path):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        prefix + name.replace(".", "_").replace("-", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def metric_reader(name: str):
+    """The ``read(rec) -> float | None`` function of a per-layer metric."""
+    return _module("bench_metric_", name,
+                   _file("metrics", name, ".py")).read
+
+
+def sampler(name: str):
+    """The plug-in module of the sampler a configuration names."""
+    if name == "__init__":                  # the contract, no plug-in
+        raise FileNotFoundError("no sampler plug-in named '__init__' "
+                                "(bench/samplers/__init__.py)")
+    return _module("bench_sampler_", name, _file("samplers", name, ".py"))
 
 
 def cell(bench: dict, workload: str) -> tuple[dict, dict, dict]:

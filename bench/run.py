@@ -9,22 +9,25 @@
 
 The cell (``BENCHMARK.json``) names a configuration
 (``bench/configs/<name>.json``: the studies and their history) and a mix
-(``bench/mixes/<name>.json``: the open-loop traffic).  This process never
-imports JAX.  It starts ``bench/serve.py``, which owns the chip, writes the
-history, warms the sampler's shapes and runs ``repro.core.service
---workers 1``; then it resolves the studies over HTTP, runs the schedule's
-warm-up seconds and the measured window through ``bench/loadgen.py``.
-Afterwards it kills the service, has the journal replayed by a process of
-its own, and checks what the window produced against the plain reference
-(``bench/reference.py``) and the replay.
+(``bench/mixes/<name>.json``: the open-loop traffic); the configuration's
+sampler names its plug-in (``bench/samplers/<name>.py``), which holds all
+that is particular to one sampler.  This process never imports JAX.  It
+starts ``bench/serve.py``, which owns the chip, writes the history, warms
+the sampler's shapes and runs ``repro.core.service --workers 1``; then it
+resolves the studies over HTTP, runs the schedule's warm-up seconds and
+the measured window through ``bench/loadgen.py``.  Afterwards it kills the
+service, has the journal replayed by a process of its own, and checks what
+the window produced against the plain reference (through the plug-in) and
+the replay.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics with ``--trace 0``,
 its per-layer metrics with ``--trace 1``), ``device``, with ``--trace 1``
-``breakdown``, and last ``checks``: each number compared with its limit,
-which are also the last lines on stderr.  Without a TPU, with fewer chips
-than the cell asks for, or with ``--rehearse``, no result is printed and
-the exit code is 1.
+``breakdown`` (with ``idle_by_span``, the device's idle time by the
+service span open on the host), and last ``checks``: each number
+compared with its limit, which are also the last lines on stderr.
+Without a TPU, with fewer chips than the cell asks for, or with
+``--rehearse``, no result is printed and the exit code is 1.
 """
 from __future__ import annotations
 
@@ -49,7 +52,6 @@ sys.path.insert(0, str(HERE))
 import numpy as np  # noqa: E402
 
 import plan as plans  # noqa: E402
-import reference  # noqa: E402
 import registry  # noqa: E402
 from loadgen import ASK, TELL, Load  # noqa: E402
 from spaces import INTS, SPACES, Codec, intermediates  # noqa: E402
@@ -195,11 +197,12 @@ def read_back(plan_path: str, journal: str, env: dict) -> dict:
 
 
 # ------------------------------------------------------------ the checks
-def check_proposals(calls: list[dict], served: list[dict],
+def check_proposals(sampler, calls: list[dict], served: list[dict],
                     studies: list[dict], objective, seed: int) -> dict:
-    """The sampled calls against the reference, with the observations the
-    run holds; each call's top candidate against a proposal the service
-    served; every served proposal inside its space."""
+    """The sampled calls against the reference (the plug-in's ``check``),
+    with the observations the run holds; the point each call served
+    against a proposal the service served; every served proposal inside
+    its space."""
     codec = Codec(SPACES[studies[0]["space"]]())
     if any(s["space"] != studies[0]["space"] for s in studies):
         raise RunError("the check takes studies of one space")
@@ -214,13 +217,13 @@ def check_proposals(calls: list[dict], served: list[dict],
         if served else np.zeros((0, codec.dim))
     for t, u in zip(served, points):
         known[objective(t["study"], t["params"])] = u
-    out = reference.check_calls(calls, known, history, points)
+    out = sampler.check(calls, known, history, points)
     by_key: dict = {}
     for t in served:
         by_key.setdefault(_coarse(codec, t["params"]), []).append(t["params"])
     mismatch = 0
     for c in calls:
-        p = codec.from_unit(c["out"][0])
+        p = codec.from_unit(sampler.top(c))
         if not any(codec.same(p, q)
                    for q in by_key.get(_coarse(codec, p), [])):
             mismatch += 1
@@ -311,6 +314,14 @@ def sweep_table(log: list[tuple], steps: list) -> list[dict]:
     return rows
 
 
+def top_rows(rows: list, n: int = 10) -> list:
+    """The ``n`` largest ``[name, seconds]`` rows, the rest summed into a
+    last ``other`` row so that the rows keep their total."""
+    if len(rows) <= n:
+        return rows
+    return rows[:n - 1] + [["other", sum(s for _n, s in rows[n - 1:])]]
+
+
 def _json_num(x):
     return None if isinstance(x, float) and not math.isfinite(x) else x
 
@@ -319,6 +330,7 @@ def _json_num(x):
 def run(args) -> int:
     bench = registry.benchmark()
     cell, config, mix = registry.cell(bench, args.workload)
+    sampler = registry.sampler(config["studies"]["sampler"]["name"])
     studies = plans.studies(config, args.seed)
     if args.sweep:
         rates = [float(r) for r in args.sweep.split(",")]
@@ -382,7 +394,7 @@ def run(args) -> int:
         got = launcher.reply("collect", COLLECT_S)
         launcher.kill()
         readback = read_back(plan_path, journal, env)
-        calls = reference.load_calls(os.path.join(tmp, "calls.npz"))
+        calls = sampler.load(os.path.join(tmp, "calls.npz"))
     except BaseException:
         if launcher is not None:
             launcher.kill()
@@ -397,7 +409,8 @@ def run(args) -> int:
         return 0
 
     t_check = time.perf_counter()
-    found = check_proposals(calls, load.served, studies, objective, args.seed)
+    found = check_proposals(sampler, calls, load.served, studies, objective,
+                            args.seed)
     found.update(check_storage(load.acks, readback))
     found["sampled_calls"] = len(calls)
     correct, checks = judge(found, config["correct"])
@@ -430,6 +443,9 @@ def run(args) -> int:
         dev["busy_s"], dev["window_s"] = tr["busy_s"], tr["window_s"]
         result["breakdown"] = {"device_ops": tr["top_ops"],
                                "idle_gaps": tr["idle_gaps"]}
+        if got.get("spans"):
+            result["breakdown"]["idle_by_span"] = top_rows(
+                got["spans"]["idle_by_span"])
     result["generator"] = {"late_p99_ms": nums["late_p99_ms"],
                            "late_max_ms": nums["late_max_ms"],
                            "setup_s": setup_s,
@@ -440,6 +456,8 @@ def run(args) -> int:
                            "gc_max_s": max(got["gc_s"], default=0.0),
                            "calls_unmatched": got["calls_unmatched"]}
     result["checks"] = checks
+    print(f"metrics: {json.dumps(metrics, default=_json_num)}",
+          file=sys.stderr)
     print(f"window: {json.dumps(nums)}", file=sys.stderr)
     for name, c in checks.items():
         bound = (f"min {c['min']}" if "min" in c else f"limit {c['limit']}")

@@ -5,28 +5,33 @@
 This process owns the chip.  Before the service starts it writes the
 studies' history as the service's own snapshot (``snapshot-00000001.json``
 under ``--journal-dir``), so start-up is the restart path users take, and
-compiles the sampler program at every shape the run's schedule can reach.
-Then it calls ``repro.core.service.main`` with the flags a user passes
-(``--workers 1 --journal-dir DIR --fsync group``).
+has the sampler's plug-in (``samplers/<name>.py``, found by the name the
+studies give) compile the sampler's program at every shape the run's
+schedule can reach.  Then it calls ``repro.core.service.main`` with the
+flags a user passes (``--workers 1 --journal-dir DIR --fsync group``).
 
 A control thread reads one JSON command per line on stdin and answers on
 stdout with a ``BENCH {...}`` line:
 
 * ``window_start`` / ``window_stop``: opens and closes the measured window:
-  the compile-event count, the record of sampler calls, the WAL fsync
-  count and, when asked, the profiler trace;
+  the compile-event count, the plug-in's record of sampler calls, the WAL
+  fsync count and the dispatch lanes' counters (``/api/v2/health``) and,
+  when asked, the profiler trace with the service's own spans enabled
+  (``repro.core.tracing``) for just as long;
 * ``collect``: peak device memory, the counts, the host spans inside the
-  window (WAL compactions, garbage-collector passes), the reduced trace,
-  and the recorded sampler calls written to ``<plan dir>/calls.npz``.
+  window (WAL compactions, garbage-collector passes), the reduced trace
+  and the service's spans reduced from it (``bench/spans.py``), and the
+  recorded sampler calls written to ``<plan dir>/calls.npz``.
 
 The harness then kills this process (SIGKILL: nothing is flushed on the
 way out) and runs it again with ``--readback``, which opens the journal
 (a replay) and writes every trial the run created to
 ``<plan dir>/readback.json``; that run starts no service.
 
-``--control high`` puts the plain Parzen log-density, contracted in three
-bfloat16 passes, in the program's place (the precision below the one the
-kernels state); ``--fault`` plants a fault for the harness's tests.
+``--control high`` has the plug-in put the sampler's plain scoring,
+computed one precision step below the one the kernels state, in the
+program's place; ``--fault`` plants a fault for the harness's tests, the
+plug-in's own or one of those below.
 """
 from __future__ import annotations
 
@@ -46,6 +51,7 @@ sys.path.insert(0, str(HERE.parent / "src"))
 import numpy as np  # noqa: E402
 
 import plan as plans  # noqa: E402
+import registry  # noqa: E402
 from spaces import SPACES, intermediates  # noqa: E402
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -86,135 +92,6 @@ def write_history(plan: dict, journal_dir: str) -> None:
         f.write(json.dumps({"covers": 1, "state": store.state_record()},
                            allow_nan=False))
     os.replace(path + ".tmp", path)
-
-
-def warm_shapes(plan: dict) -> set:
-    """(dim, good rows, bad rows, candidates, top-k) of every sampler call
-    the schedule can make, by the sampler's own bucketing rules."""
-    from repro.core.obs_cache import pad_pow2
-    from repro.core.samplers import make_sampler
-
-    shapes = set()
-    b = plan["batch"]
-    for s, (lo, hi) in zip(plan["studies"], plan["ranges"]):
-        sampler = make_sampler(dict(s["sampler"]))
-        dim = len(SPACES[s["space"]]())
-        chunk = 1 if b == 1 else max(sampler.liar_chunk, -(-b // 8))
-        ks = {1} if b == 1 else {min(chunk, b - g) for g in range(0, b, chunk)}
-        for n in range(lo, hi + 1):
-            ng = sampler._n_good(n)
-            nb = n - ng if n > ng else ng
-            for k in ks:
-                shapes.add((dim, pad_pow2(ng), pad_pow2(nb),
-                            sampler._pool(k), k))
-    return shapes
-
-
-def warm(shapes: set) -> None:
-    import jax
-    import jax.numpy as jnp
-    from repro.core.samplers import tpe
-
-    for dim, ng, nb, pool, k in sorted(shapes):
-        mg, mb = np.zeros(ng), np.zeros(nb)
-        mg[0] = mb[0] = 1.0
-        # built as the sampler builds its buffers, so the call hits the
-        # same compiled program
-        args = (jnp.asarray(np.zeros((ng, dim))), jnp.asarray(mg),
-                jnp.asarray(np.zeros((nb, dim))), jnp.asarray(mb))
-        key = jax.random.PRNGKey(0)
-        np.asarray(tpe._tpe_propose(*args, key, pool)[:k])
-
-
-class Recorder:
-    """Inside the window, wraps the TPE sampler's observation view, its
-    good/bad split and its proposal program.  It counts proposal calls by
-    shape and keeps a seeded sample of them, each with the observations
-    (X, y and the count of real rows) that its split was made from, and
-    the latest call at the largest history.  Only the small buffers (the
-    good rows, both masks and the ranked candidates) stay on the device
-    until the window has closed; X and y are host arrays the cache never
-    changes in place."""
-
-    KEEP = 8                        # recent views and splits to match
-
-    def __init__(self, fn, seed: int, sample: int):
-        self.fn = fn
-        self.lock = threading.Lock()
-        self.active = False
-        self.rng = np.random.default_rng([seed, 11])
-        self.cap = sample
-        self.shapes: list[tuple] = []
-        self.views: dict = {}
-        self.splits: dict = {}
-        self.kept: list[tuple] = []
-        self.longest: tuple | None = None
-        self.unmatched = 0
-
-    def _remember(self, table: dict, key, value) -> None:
-        table[key] = value
-        while len(table) > self.KEEP:
-            del table[next(iter(table))]
-
-    def install(self, tpe) -> None:
-        rec, sampler = self, tpe.TPESampler
-        view, split = sampler.observations_pending, sampler._split_xy
-
-        def observations_pending(cls, *a, **kw):
-            X, y, n_obs = out = view(*a, **kw)
-            if rec.active:
-                with rec.lock:
-                    rec._remember(rec.views, id(X), (X, y, n_obs))
-            return out
-
-        def split_xy(self, space, X, y):
-            out = split(self, space, X, y)
-            if rec.active:
-                with rec.lock:
-                    v = rec.views.get(id(X))
-                    if v is not None and v[0] is X:
-                        rec._remember(rec.splits, id(out[0]), (out, *v))
-            return out
-        sampler.observations_pending = classmethod(observations_pending)
-        sampler._split_xy = split_xy
-        tpe._tpe_propose = self
-
-    def __call__(self, xg, mg, xb, mb, key, n_candidates):
-        out = self.fn(xg, mg, xb, mb, key, n_candidates)
-        if self.active:
-            with self.lock:
-                self.shapes.append((xg.shape[0], xb.shape[0], xg.shape[1],
-                                    int(n_candidates)))
-                s = self.splits.get(id(xg))
-                if s is None or s[0][0] is not xg:
-                    self.unmatched += 1
-                    return out
-                call = (s[1], s[2], s[3], xg, mg, mb, out)
-                # a uniform sample of the window's calls (reservoir)
-                n = len(self.shapes)
-                if len(self.kept) < self.cap:
-                    self.kept.append(call)
-                else:
-                    j = int(self.rng.integers(0, n))
-                    if j < self.cap:
-                        self.kept[j] = call
-                if self.longest is None or len(s[2]) >= len(self.longest[1]):
-                    self.longest = call
-        return out
-
-    def save(self, path: str) -> int:
-        with self.lock:
-            calls = list(self.kept)
-            if self.longest is not None and not any(
-                    c is self.longest for c in calls):
-                calls.append(self.longest)
-        arrays = {}
-        for i, call in enumerate(calls):
-            for name, a in zip(("X", "y", "n_obs", "xg", "mg", "mb", "out"),
-                               call):
-                arrays[f"{i}_{name}"] = np.asarray(a)
-        np.savez(path, n=len(calls), **arrays)
-        return len(calls)
 
 
 class Spans:
@@ -268,15 +145,18 @@ class CompileCounter:
             self.names.append(str(kwargs.get("fun_name", "?")))
 
 
-def health_fsyncs(url: str) -> int | None:
+def health(url: str) -> tuple:
+    """(WAL fsyncs, the dispatch lanes' counters) as the service serves
+    them."""
     with urllib.request.urlopen(url + "/api/v2/health", timeout=30) as r:
-        return json.loads(r.read())["storage"].get("fsyncs")
+        h = json.loads(r.read())
+    return h["storage"].get("fsyncs"), h.get("frontend")
 
 
 class Control:
-    def __init__(self, plan: dict, out_dir: str, recorder: Recorder,
+    def __init__(self, plan: dict, out_dir: str, sampler, recorder,
                  compiles: CompileCounter, spans: Spans):
-        self.plan, self.out_dir = plan, out_dir
+        self.plan, self.out_dir, self.sampler = plan, out_dir, sampler
         self.recorder, self.compiles, self.spans = recorder, compiles, spans
         self.trace_dir: str | None = None
         self.state: dict = {}
@@ -291,7 +171,9 @@ class Control:
 
     def window_start(self, msg: dict) -> dict:
         import jax
-        self.state["fsyncs0"] = health_fsyncs(msg["url"])
+
+        from repro.core import tracing
+        self.state["fsyncs0"], self.state["frontend0"] = health(msg["url"])
         self.state["t0_ns"] = time.time_ns()      # the trace's clock starts
         self.state["t0"] = time.perf_counter()
         if msg.get("trace"):
@@ -299,18 +181,22 @@ class Control:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 2
+            tracing.enable()
             jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
         self.recorder.active = self.compiles.active = True
         return {"cmd": "window_start"}
 
     def window_stop(self, msg: dict) -> dict:
         import jax
+
+        from repro.core import tracing
         self.recorder.active = self.compiles.active = False
         self.state["t1_ns"] = time.time_ns()
         self.state["t1"] = time.perf_counter()
         if self.trace_dir:
             jax.profiler.stop_trace()
-        self.state["fsyncs1"] = health_fsyncs(msg["url"])
+            tracing.disable()
+        self.state["fsyncs1"], self.state["frontend1"] = health(msg["url"])
         return {"cmd": "window_stop"}
 
     def collect(self, msg: dict) -> dict:
@@ -324,6 +210,8 @@ class Control:
                "compile_names": self.compiles.names[:20],
                "fsyncs": [self.state.get("fsyncs0"),
                           self.state.get("fsyncs1")],
+               "frontend": [self.state.get("frontend0"),
+                            self.state.get("frontend1")],
                "calls": len(self.recorder.shapes),
                "calls_unmatched": self.recorder.unmatched,
                "compaction_s": Spans.within(self.spans.compactions,
@@ -338,10 +226,21 @@ class Control:
                    os.path.join(self.out_dir, "calls.npz"))}
         if self.trace_dir:
             import devtrace
-            events = devtrace.extract(self.trace_dir)
-            out["trace"] = devtrace.reduce(
-                events, self.state["t1_ns"] - self.state["t0_ns"])
+            out.update(reduce_trace(
+                devtrace.extract(self.trace_dir),
+                self.state["t1_ns"] - self.state["t0_ns"], self.sampler))
         return out
+
+
+def reduce_trace(events: dict, window_ns: int, sampler) -> dict:
+    """The traced window reduced: the device's side (``trace``) and the
+    program's spans (``spans``), the service's own and those the sampler
+    plug-in declares."""
+    import devtrace
+    import spans
+    names = spans.names(sampler)
+    return {"trace": devtrace.reduce(events, window_ns, names),
+            "spans": spans.reduce(events, window_ns, names)}
 
 
 def readback(plan: dict, journal_dir: str, path: str) -> None:
@@ -361,12 +260,11 @@ def readback(plan: dict, journal_dir: str, path: str) -> None:
         json.dump(out, f)
 
 
-def plant_fault(fault: str) -> None:
-    """Faults the tests plant under a run to see ``correct`` turn false."""
-    from repro.core.samplers import tpe
-    if fault == "proposal_order":          # worst candidate served first
-        fn = tpe._tpe_propose
-        tpe._tpe_propose = lambda *a: fn(*a)[::-1]
+def plant_fault(fault: str, sampler_faults: dict) -> None:
+    """Faults the tests plant under a run to see ``correct`` turn false:
+    the sampler plug-in's own, and these beneath the rest of the path."""
+    if fault in sampler_faults:
+        sampler_faults[fault]()
     elif fault == "tell_ignored":          # a tell leaves the trial as it was
         from repro.core.storage import InMemoryStorage
         update = InMemoryStorage.update_trial
@@ -376,10 +274,6 @@ def plant_fault(fault: str) -> None:
                 fields = {}
             return update(self, uid, idem=idem, **fields)
         InMemoryStorage.update_trial = ignored
-    elif fault == "split_order":           # the worst rows taken as good
-        split = tpe.TPESampler._split_xy
-        tpe.TPESampler._split_xy = \
-            lambda self, space, X, y: split(self, space, X, -y)
     elif fault == "liar_value":            # in-flight rows at the best value
         from repro.core import obs_cache
         obs_cache.liar_value = lambda y, mode: float(np.min(y))
@@ -441,24 +335,25 @@ def main(argv: list[str] | None = None) -> int:
 
     import jax
     from repro.core import service
-    from repro.core.samplers import tpe
+    # the studies of one configuration share its sampler
+    sampler = registry.sampler(plan["studies"][0]["sampler"]["name"])
     if args.control == "high":
-        import control
-        tpe.parzen_log_density = control.parzen_log_density_bf16x3
-    plant_fault(args.fault)
+        sampler.control_high()
+    plant_fault(args.fault, sampler.FAULTS)
     spans = Spans()
     spans.install()
     compiles = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(compiles)
-    shapes = warm_shapes(plan)
+    shapes = sampler.warm_shapes(plan)
     t1 = time.perf_counter()
-    warm(shapes)
+    sampler.warm(shapes)
     print(f"bench: history {t_hist:.2f} s, runtime up "
           f"{t1 - t0:.2f} s, {len(shapes)} sampler shapes warmed in "
           f"{time.perf_counter() - t1:.2f} s", file=sys.stderr, flush=True)
-    recorder = Recorder(tpe._tpe_propose, plan["seed"], plan["sample_calls"])
-    recorder.install(tpe)
-    control_thread = Control(plan, out_dir, recorder, compiles, spans)
+    recorder = sampler.Recorder(plan["seed"], plan["sample_calls"])
+    recorder.install()
+    control_thread = Control(plan, out_dir, sampler, recorder, compiles,
+                             spans)
     threading.Thread(target=control_thread.run, daemon=True).start()
     return service.main(["--port", "0", "--workers", "1", "--journal-dir",
                          args.journal_dir, *plan["service_flags"]])

@@ -3,9 +3,11 @@ the host-layer metrics read.
 
 The service writes its spans (``repro.core.tracing``) into the profiler's
 trace while tracing is enabled: one line per host thread, on the clock of
-the device planes.  ``reduce`` (plain Python and numpy) works on the
-events ``devtrace.extract`` returns, over the same window
-``[0, window_ns)``, and gives:
+the device planes.  The spans a run reads are the service's own
+(``SPANS``) and those its sampler plug-in declares (``names``).
+``reduce`` (plain Python and numpy) works on the events
+``devtrace.extract`` returns, over the same window ``[0, window_ns)``,
+and keeps the spans it is given by name; it gives:
 
 * ``per_name``: per span name, ``[count, seconds]`` of the spans that
   start in the window, however long;
@@ -27,13 +29,19 @@ import devtrace
 
 REQUEST = ("http.ask", "http.report", "http.tell", "http.request")
 SPANS = REQUEST + ("http.read", "study.lock_wait", "obs_cache.sync",
-                   "tpe.propose", "tpe.readback", "pruner.should_prune",
-                   "wal.append", "wal.fsync", "wal.compact")
+                   "pruner.should_prune", "wal.append", "wal.fsync",
+                   "wal.compact")
 NO_SPAN = "no span"
 
 
-def reduce(trace: dict, window_ns: int) -> dict:
-    threads = _threads(trace)
+def names(sampler) -> tuple:
+    """The spans a run reads: the service's own and those of its sampler
+    plug-in (``sampler.SPANS``)."""
+    return SPANS + tuple(sampler.SPANS)
+
+
+def reduce(trace: dict, window_ns: int, names: tuple) -> dict:
+    threads = _threads(trace, frozenset(names))
     per_name: dict[str, list] = {}
     for spans in threads:
         for s, e, name in spans:
@@ -57,16 +65,17 @@ def reduce(trace: dict, window_ns: int) -> dict:
                                    key=lambda x: -x[1])}
 
 
-def _threads(trace: dict) -> list[list[tuple]]:
-    """Per host line, its program spans as (start, end, name), sorted by
-    start with the outer of two spans that start together first."""
+def _threads(trace: dict, names: frozenset) -> list[list[tuple]]:
+    """Per host line, its spans named in ``names`` as (start, end, name),
+    sorted by start with the outer of two spans that start together
+    first."""
     out = []
     for plane in trace["planes"]:
         if not plane["name"].startswith("/host:"):
             continue
         for line in plane["lines"]:
             spans = sorted(((s, s + d, n) for n, s, d in line["events"]
-                            if n in SPANS), key=lambda x: (x[0], -x[1]))
+                            if n in names), key=lambda x: (x[0], -x[1]))
             if spans:
                 out.append(spans)
     return out

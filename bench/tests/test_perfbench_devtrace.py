@@ -51,6 +51,17 @@ def test_idle_gaps_go_to_the_host_event_that_covers_most_of_them():
     assert sum(gaps.values()) == pytest.approx(30e-6 - r["busy_s"])
 
 
+def test_idle_gaps_leave_the_services_spans_to_idle_by_span():
+    # a request span around both gaps would outweigh the JAX events
+    # inside them; it is read by ``bench/spans.py`` instead
+    t = _trace()
+    t["planes"][1]["lines"].append({"name": "lane", "events": [
+        ["http.ask", 0, 30_000], ["tpe.propose", 3_000, 17_000]]})
+    names = ("http.ask", "tpe.propose")
+    assert devtrace.reduce(t, 30_000, names)["idle_gaps"] == \
+        devtrace.reduce(_trace(), 30_000)["idle_gaps"]
+
+
 def test_events_outside_the_window_do_not_count():
     r = devtrace.reduce(_trace(), 5_000)
     assert r["busy_s"] == pytest.approx(2_500e-9)

@@ -1,5 +1,8 @@
-"""The harness finds every configuration, mix and metric by its name."""
+"""The harness finds every configuration, mix, metric and sampler plug-in
+by its name."""
 import json
+import re
+import shutil
 
 import pytest
 
@@ -58,3 +61,41 @@ def test_benchmark_json_is_valid_json_with_the_contract_keys():
     bench = json.loads((registry.ROOT / "BENCHMARK.json").read_text())
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
+
+
+CONTRACT = ("SPANS", "warm_shapes", "warm", "Recorder", "load", "check",
+            "top", "control_high", "FAULTS")
+
+
+def test_every_configuration_has_its_sampler_plug_in():
+    for c in registry.benchmark()["configs"]:
+        name = registry.config(c["name"])["studies"]["sampler"]["name"]
+        assert (registry.HERE / "samplers" / f"{name}.py").is_file()
+        plug_in = registry.sampler(name)
+        assert all(hasattr(plug_in, k) for k in CONTRACT), name
+        assert all(callable(f) for f in plug_in.FAULTS.values())
+
+
+def test_a_plug_in_dropped_into_samplers_is_found_by_name(tmp_path,
+                                                         monkeypatch):
+    bench = tmp_path / "bench"
+    shutil.copytree(registry.HERE / "samplers", bench / "samplers")
+    (bench / "samplers" / "toy.py").write_text(
+        '"""A sampler no configuration runs."""\nFAULTS = {"none": None}\n')
+    monkeypatch.setattr(registry, "HERE", bench)
+    assert registry.sampler("toy").FAULTS == {"none": None}
+    assert registry.sampler("tpe").__file__ == str(
+        bench / "samplers" / "tpe.py")
+    with pytest.raises(FileNotFoundError, match=re.escape(
+            "no sampler plug-in named 'gp' (bench/samplers/gp.py)")):
+        registry.sampler("gp")
+
+
+@pytest.mark.parametrize("name,error", [
+    ("gp", FileNotFoundError),            # no file
+    ("__init__", FileNotFoundError),      # the contract, not a plug-in
+    ("../configs", ValueError), ("a/b", ValueError), ("", ValueError),
+    (".tpe", ValueError), ("x" * 65, ValueError)])
+def test_an_unknown_or_illegal_sampler_name_is_refused(name, error):
+    with pytest.raises(error):
+        registry.sampler(name)

@@ -1,17 +1,22 @@
 """The reduction of the service's own spans (``bench/spans.py``) and the
-host-layer metrics that read it, on a hand-made trace and on a record of
-a window traced on the chip."""
+host-layer metrics that read it, on a hand-made trace, on a record of a
+window traced on the chip and in a traced rehearsal."""
 import json
+import shutil
 from pathlib import Path
 
 import pytest
 
 import registry
+import run
 import spans
+from _rehearse import rehearse
 
 MS = 1_000_000
 RECORD = Path(__file__).resolve().parent / "fixtures" / "spans_record.json"
 
+# the spans a run of the TPE configuration reads
+NAMES = spans.names(registry.sampler("tpe"))
 READERS = ("frontend.lane_ms_per_trial", "frontend.lane_wait_ms",
            "study.lock_wait_ms", "obs_cache.sync_ms", "tpe.host_ms",
            "tpe.readback_ms", "pruner.report_ms", "wal.append_ms")
@@ -41,7 +46,7 @@ def _trace():
 
 
 def test_per_name_counts_spans_starting_in_the_window():
-    r = spans.reduce(_trace(), 3000 * MS)
+    r = spans.reduce(_trace(), 3000 * MS, NAMES)
     assert r["per_name"] == {
         "http.ask": [1, pytest.approx(0.85)],
         "study.lock_wait": [1, pytest.approx(0.02)],
@@ -55,7 +60,7 @@ def test_per_name_counts_spans_starting_in_the_window():
 
 
 def test_idle_time_goes_to_the_innermost_span_of_a_request_first():
-    r = spans.reduce(_trace(), 3000 * MS)
+    r = spans.reduce(_trace(), 3000 * MS, NAMES)
     idle = dict(r["idle_by_span"])
     # gaps [0, 100), [200, 1000), [1100, 3000) ms
     assert idle == {
@@ -77,7 +82,7 @@ def test_idle_time_goes_to_the_innermost_span_of_a_request_first():
 def test_no_device_plane_makes_the_whole_window_idle():
     t = _trace()
     t["planes"] = t["planes"][1:]
-    idle = dict(spans.reduce(t, 3000 * MS)["idle_by_span"])
+    idle = dict(spans.reduce(t, 3000 * MS, NAMES)["idle_by_span"])
     assert sum(idle.values()) == pytest.approx(3.0)
     assert idle["tpe.propose"] == pytest.approx(0.35)      # 150-500
 
@@ -96,7 +101,7 @@ def _lanes(handled, wait_ns):
 
 
 def test_readers_on_a_reduced_trace():
-    rec = _record(spans.reduce(_trace(), 3000 * MS),
+    rec = _record(spans.reduce(_trace(), 3000 * MS, NAMES),
                   [_lanes(10, 1_000_000), _lanes(14, 9_000_000)])
     got = {name: registry.metric_reader(name)(rec) for name in READERS}
     assert got == {
@@ -129,3 +134,51 @@ def test_readers_on_a_recorded_chip_record():
     assert sum(idle.values()) == pytest.approx(
         rec["window_s"] - rec["busy_s"])
     assert idle["no span"] < 0.1 * rec["window_s"]
+
+
+def test_a_traced_rehearsal_hands_the_readers_spans_and_lane_counters():
+    # the launcher enables the service's spans for the traced window and
+    # collects the lanes' counters at its edges; each reader finds a number
+    out = rehearse("campaign-tpe-overload", 2**31 + 23, "--trace", "1")
+    assert out["correct"] is True, out["stderr"][-3000:]
+    got = {n: (out["metrics"].get(n) or {}).get("value") for n in READERS}
+    assert all(isinstance(v, float) and v > 0 for v in got.values()), got
+
+
+@pytest.mark.parametrize("n_rows,kept", [(3, 3), (10, 10), (14, 10)])
+def test_the_breakdown_keeps_ten_rows_and_their_total(n_rows, kept):
+    rows = [[f"span{i}", 1.0 / (i + 1)] for i in range(n_rows)]
+    got = run.top_rows(rows)
+    assert len(got) == kept
+    assert sum(s for _n, s in got) == pytest.approx(sum(s for _n, s in rows))
+    assert got[:9] == rows[:9]
+    if n_rows > kept:
+        assert got[-1][0] == "other"
+
+
+def test_a_plug_in_s_own_spans_are_read_and_left_out_of_idle_gaps(
+        tmp_path, monkeypatch):
+    # a sampler added with its file alone declares a span the service's
+    # list does not name; the launcher's reduction reads it by that name
+    import serve
+    bench = tmp_path / "bench"
+    shutil.copytree(registry.HERE / "samplers", bench / "samplers")
+    (bench / "samplers" / "toy.py").write_text(
+        '"""A sampler no configuration runs."""\nSPANS = ("toy.solve",)\n')
+    monkeypatch.setattr(registry, "HERE", bench)
+    toy = registry.sampler("toy")
+    t = _trace()
+    t["planes"][1]["lines"].append({"name": "python", "events": [
+        _ev("toy.solve", 1150, 2900), _ev("XlaLinearize", 1200, 1210)]})
+    got = serve.reduce_trace(t, 3000 * MS, toy)
+    assert got["spans"]["per_name"]["toy.solve"] == [1, pytest.approx(1.75)]
+    assert "tpe.propose" not in got["spans"]["per_name"]
+    # 1150-1200, 1300-2000, 2100-2900: no request open, and the toy span
+    # opened after the compaction
+    idle = dict(got["spans"]["idle_by_span"])
+    assert idle["toy.solve"] == pytest.approx(1.55)
+    assert sum(idle.values()) == pytest.approx(2.8)
+    # the device's gap [1100, 3000) goes to the JAX event inside it
+    gaps = dict(got["trace"]["idle_gaps"])
+    assert "toy.solve" not in gaps and "wal.compact" not in gaps
+    assert gaps["XlaLinearize"] == pytest.approx(1.9)
