@@ -2,5 +2,6 @@ import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
-if str(BENCH) not in sys.path:
-    sys.path.insert(0, str(BENCH))
+for path in (BENCH, BENCH.parent / "src"):   # the harness, the program
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))

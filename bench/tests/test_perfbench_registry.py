@@ -7,6 +7,7 @@ import shutil
 import pytest
 
 import registry
+from repro.core.samplers import known_samplers
 
 
 def test_every_name_in_benchmark_json_has_its_file():
@@ -76,13 +77,21 @@ def test_every_configuration_has_its_sampler_plug_in():
         assert all(callable(f) for f in plug_in.FAULTS.values())
 
 
-def test_a_plug_in_dropped_into_samplers_is_found_by_name(tmp_path,
-                                                         monkeypatch):
+def _samplers_copy(tmp_path, monkeypatch):
+    """The registry pointed at a copy of ``samplers/`` that has no plug-in
+    for ``gp``, whichever plug-ins the benchmark has."""
     bench = tmp_path / "bench"
     shutil.copytree(registry.HERE / "samplers", bench / "samplers")
+    (bench / "samplers" / "gp.py").unlink(missing_ok=True)
+    monkeypatch.setattr(registry, "HERE", bench)
+    return bench
+
+
+def test_a_plug_in_dropped_into_samplers_is_found_by_name(tmp_path,
+                                                         monkeypatch):
+    bench = _samplers_copy(tmp_path, monkeypatch)
     (bench / "samplers" / "toy.py").write_text(
         '"""A sampler no configuration runs."""\nFAULTS = {"none": None}\n')
-    monkeypatch.setattr(registry, "HERE", bench)
     assert registry.sampler("toy").FAULTS == {"none": None}
     assert registry.sampler("tpe").__file__ == str(
         bench / "samplers" / "tpe.py")
@@ -96,6 +105,19 @@ def test_a_plug_in_dropped_into_samplers_is_found_by_name(tmp_path,
     ("__init__", FileNotFoundError),      # the contract, not a plug-in
     ("../configs", ValueError), ("a/b", ValueError), ("", ValueError),
     (".tpe", ValueError), ("x" * 65, ValueError)])
-def test_an_unknown_or_illegal_sampler_name_is_refused(name, error):
+def test_an_unknown_or_illegal_sampler_name_is_refused(name, error, tmp_path,
+                                                       monkeypatch):
+    _samplers_copy(tmp_path, monkeypatch)
     with pytest.raises(error):
         registry.sampler(name)
+
+
+@pytest.mark.parametrize("name", known_samplers())
+def test_a_plug_in_is_found_for_every_sampler_the_service_registers(
+        name, tmp_path, monkeypatch):
+    bench = _samplers_copy(tmp_path, monkeypatch)
+    (bench / "samplers" / f"{name}.py").write_text(
+        f'"""A throwaway plug-in."""\nNAME = {name!r}\n')
+    plug_in = registry.sampler(name)
+    assert plug_in.NAME == name
+    assert plug_in.__file__ == str(bench / "samplers" / f"{name}.py")

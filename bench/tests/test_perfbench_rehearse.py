@@ -8,10 +8,13 @@ import time
 
 import pytest
 
+import registry
 from _rehearse import RUN, rehearse
 
+BENCH = registry.benchmark()
 
-@pytest.mark.parametrize("workload", ["campaign-tpe-overload"])
+
+@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
 def test_rehearsal_runs_every_phase_and_prints_no_result(workload):
     out = rehearse(workload, 2**31 + 5)
     assert out["rc"] == 1, out["stderr"][-3000:]
@@ -19,7 +22,8 @@ def test_rehearsal_runs_every_phase_and_prints_no_result(workload):
     assert out["correct"] is True, out["stderr"][-3000:]
     assert out["checks"]["sampled_calls"] >= 1
     assert out["checks"]["tells_lost"] == out["checks"]["reports_lost"] == 0
-    assert set(out["metrics"]) == {"trials_per_s", "setup_s"}
+    assert set(out["metrics"]) == {
+        m["name"] for m in registry.metrics_of(BENCH, workload, False)}
     assert "rehearsal: no result" in out["stderr"]
 
 
@@ -32,10 +36,12 @@ def test_a_measurement_without_a_chip_fails():
 def _copy_with_a_gp_cell(root):
     """The benchmark's files copied to ``root`` with one configuration
     whose sampler is ``gp`` and a cell of it, as a later PR would add
-    them: files and entries alone."""
+    them: files and entries alone.  Whatever plug-ins the benchmark has,
+    the copy has none for ``gp``."""
     here = RUN.parents[1]
     shutil.copytree(here / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    (root / "bench/samplers/gp.py").unlink(missing_ok=True)
     config = json.loads((here / "bench/configs/campaign-tpe.json").read_text())
     config["name"] = "campaign-gp"
     config["studies"]["history"] = [256, 511]
